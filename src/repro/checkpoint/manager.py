@@ -275,8 +275,11 @@ class CheckpointManager:
         Raises any error from the *previous* async save (so failures are
         observed, but off the hot path).
         """
-        self.wait()  # one in-flight save at a time; surfaces prior errors
-        host_tree = snapshot_to_host(tree)
+        # The stall the training loop pays for this save: the wait for
+        # the previous one plus the device→host snapshot.
+        with _trace.span("save_stall", "ckpt", step=step):
+            self.wait()  # one in-flight save at a time; surfaces errors
+            host_tree = snapshot_to_host(tree)
         use_delta = self.delta if delta is None else bool(delta)
 
         def _write() -> None:
@@ -560,9 +563,10 @@ class CheckpointManager:
 
         Returns ``(tree, step)`` where step is -1 for a fresh start.
         """
-        steps = self.all_steps()
-        if steps:
-            tree, step = self.restore_latest(like)
-            if tree is not None:
-                return tree, (step if step is not None else steps[-1])
-        return init_fn(), -1
+        with _trace.span("restore_or_init", "ckpt"):
+            steps = self.all_steps()
+            if steps:
+                tree, step = self.restore_latest(like)
+                if tree is not None:
+                    return tree, (step if step is not None else steps[-1])
+            return init_fn(), -1

@@ -730,27 +730,45 @@ def _leaf_layout(name: str, spec_, target) -> Dict[str, Any]:
                 f"checkpoint shape {shape}")
         sharding = getattr(target, "sharding", None)
     units: List[_Unit] = []
-    per_device: List[Tuple[Any, int]] = []
-    if sharding is None:
+    per_device: List[Tuple[Any, Any]] = []
+    whole = True
+    if sharding is not None:
+        itemsize = np.dtype(dtype).itemsize
+        device_map = sharding.addressable_devices_indices_map(shape)
+        extents: Dict[Tuple, Any] = {}
+        for index in device_map.values():
+            extents.setdefault(_index_key(index, shape), index)
+        held = sum(_nbytes(_shard_shape(i, shape), itemsize)
+                   for i in extents.values())
+        # Where this process holds every shard (one host), the leaf is
+        # read in one sweep and each device's shard sliced out of it: a
+        # column-sharded leaf would otherwise take one read per row.
+        whole = held == spec_["nbytes"]
+        if whole:
+            per_device = list(device_map.items())
+        else:
+            by_extent: Dict[Tuple, int] = {}
+            for device, index in device_map.items():
+                key = _index_key(index, shape)
+                if key not in by_extent:
+                    sshape = _shard_shape(index, shape)
+                    by_extent[key] = len(units)
+                    units.append(_Unit(
+                        layout.shard_runs(shape, index, itemsize), sshape,
+                        _nbytes(sshape, itemsize)))
+                per_device.append((device, by_extent[key]))
+    if whole:
         runs = [(0, 0, spec_["nbytes"])] if spec_["nbytes"] else []
         units.append(_Unit(runs, shape, spec_["nbytes"]))
-    else:
-        itemsize = np.dtype(dtype).itemsize
-        by_extent: Dict[Tuple, int] = {}
-        for device, index in \
-                sharding.addressable_devices_indices_map(shape).items():
-            key = _index_key(index, shape)
-            if key not in by_extent:
-                runs = layout.shard_runs(shape, index, itemsize)
-                sshape = _shard_shape(index, shape)
-                nbytes = (int(np.prod(sshape, dtype=np.int64)) * itemsize
-                          if sshape else itemsize)
-                by_extent[key] = len(units)
-                units.append(_Unit(runs, sshape, nbytes))
-            per_device.append((device, by_extent[key]))
     return {"name": name, "spec": spec_, "target": target,
             "dtype": dtype, "shape": shape, "sharding": sharding,
-            "units": units, "per_device": per_device, "pending": 0}
+            "whole": whole, "units": units, "per_device": per_device,
+            "pending": 0}
+
+
+def _nbytes(shard_shape, itemsize: int) -> int:
+    return (int(np.prod(shard_shape, dtype=np.int64)) * itemsize
+            if shard_shape else itemsize)
 
 
 def _restore_pipelined(r: ScdaReader, wanted, prefetch_bytes: int) \
@@ -844,8 +862,14 @@ def _restore_pipelined(r: ScdaReader, wanted, prefetch_bytes: int) \
 def _finalize_leaf(leaf: Dict[str, Any]):
     """Assemble a completed leaf from its unit buffers (host → device)."""
     dtype, shape = leaf["dtype"], leaf["shape"]
-    if leaf["sharding"] is None:
-        return leaf["units"][0].arr.view(dtype).reshape(shape)
+    if leaf["whole"]:
+        full = leaf["units"][0].arr.view(dtype).reshape(shape)
+        if leaf["sharding"] is None:
+            return full
+        arrays = [jax.device_put(full[index], device)
+                  for device, index in leaf["per_device"]]
+        return jax.make_array_from_single_device_arrays(
+            shape, leaf["sharding"], arrays)
     arrays = [
         jax.device_put(
             leaf["units"][ui].arr.view(dtype)

@@ -433,7 +433,7 @@ class FileBackend:
         A failure that crossed the executor boundary has lost the
         submitting stack, so the submission-time op context (stage, path,
         offset, bytes) is re-attached here: as ``op_context``/``stage``
-        attributes plus an exception note (3.11+), never by rewriting the
+        attributes plus an exception note, never by rewriting the
         message — background errors must stay byte-identical to the
         foreground ones the pipeline fuzz compares against.
         """
@@ -468,13 +468,8 @@ class FileBackend:
         err.stage = stage
         err.op_context = {"stage": stage, "path": self.path,
                           "offset": offset, "bytes": nbytes}
-        note = getattr(err, "add_note", None)
-        if note is not None:  # Python 3.11+
-            try:
-                note(f"stage: {stage} ({self.path} @ {offset}, "
+        err.add_note(f"stage: {stage} ({self.path} @ {offset}, "
                      f"{nbytes} bytes)")
-            except TypeError:  # pragma: no cover - exotic BaseExceptions
-                pass
         c = _trace.collector()
         if c is not None:
             c.event("error", "pipeline", stage=stage, path=self.path,

@@ -22,10 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 
 def _ssm_kernel(decay_ref, inc_ref, c_ref, y_ref, h_ref, *, chunk: int):
     c = pl.program_id(2)
@@ -42,12 +38,15 @@ def _ssm_kernel(decay_ref, inc_ref, c_ref, y_ref, h_ref, *, chunk: int):
     h_ref[...] = jax.lax.fori_loop(0, chunk, step, h_ref[...])
 
 
-def ssm_scan_kernel(decay, inc, C, *, chunk: int = 128,
+def ssm_scan_kernel(decay, inc, C, *, chunk: int = 16,
                     d_block: int = 256, interpret: bool = False):
     """decay/inc: (B, S, d, N) f32; C: (B, S, N) f32 → y: (B, S, d).
 
     The recurrence runs in f32 regardless of input dtype (state stability);
     S must divide by ``chunk`` (pad upstream), d by ``d_block`` (clamped).
+    A (chunk, d_block, N) block pads N = 16 to 128 lanes in VMEM, so the
+    defaults keep the two double-buffered inputs at 8 MiB, inside v5e's
+    16 MiB scoped VMEM (chunk 32 already exceeds it).
     """
     B, S, d, N = decay.shape
     chunk = min(chunk, S)
@@ -71,7 +70,7 @@ def ssm_scan_kernel(decay, inc, C, *, chunk: int = 128,
                                lambda b, dblk, c: (b, c, dblk)),
         out_shape=jax.ShapeDtypeStruct((B, S, d), jnp.float32),
         scratch_shapes=[pltpu.VMEM((d_block, N), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(decay.astype(jnp.float32), inc.astype(jnp.float32),

@@ -14,6 +14,7 @@ import logging
 import jax
 
 from repro.configs import REGISTRY, get_config, smoke
+from repro.launch.compile_cache import configure_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.optim.adamw import AdamWConfig
 from repro.train.loop import TrainLoopConfig, train
@@ -39,6 +40,7 @@ def main() -> None:
     args = ap.parse_args()
 
     logging.basicConfig(level=logging.INFO, format="%(name)s: %(message)s")
+    configure_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = smoke(cfg)

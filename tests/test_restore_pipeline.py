@@ -8,6 +8,7 @@ at every reading partition, and every failure must raise the same
 ScdaError the serial path raises (no hangs, no leaked futures).
 """
 import os
+import types
 
 import numpy as np
 import pytest
@@ -428,3 +429,35 @@ def test_short_chunk_raises_scda_error_not_valueerror():
         pytree_io._scatter_chunks_np(runs, chunks, 1024,
                                      np.empty(2048, np.uint8))
     assert ei.value.code == ScdaErrorCode.CORRUPT_CHECKSUM
+
+
+class _HalfRows:
+    """A sharding of which this process holds only the first half of the
+    rows, as one host of two would."""
+
+    def __init__(self, device):
+        self.device = device
+
+    def addressable_devices_indices_map(self, shape):
+        return {self.device: (slice(0, shape[0] // 2), slice(0, shape[1]))}
+
+
+def test_leaf_layout_reads_whole_leaf_when_every_shard_is_held():
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.checkpoint.layout import shard_runs
+    from repro.launch.mesh import make_host_mesh
+    spec_ = {"name": "w", "dtype": "float32", "shape": [8, 6],
+             "nbytes": 8 * 6 * 4}
+    held = jax.ShapeDtypeStruct((8, 6), np.float32, sharding=NamedSharding(
+        make_host_mesh(1, 1), P("data", "model")))
+    leaf = pytree_io._leaf_layout("w", spec_, held)
+    assert leaf["whole"] and len(leaf["units"]) == 1
+    assert leaf["units"][0].runs == [(0, 0, spec_["nbytes"])]
+    # Another host holds the rest: read only this host's rows.
+    dev = jax.devices()[0]
+    part = types.SimpleNamespace(shape=(8, 6), sharding=_HalfRows(dev))
+    leaf = pytree_io._leaf_layout("w", spec_, part)
+    assert not leaf["whole"] and leaf["per_device"] == [(dev, 0)]
+    assert leaf["units"][0].runs == shard_runs(
+        (8, 6), (slice(0, 4), slice(0, 6)), 4)
