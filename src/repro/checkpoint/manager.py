@@ -278,8 +278,12 @@ class CheckpointManager:
         # The stall the training loop pays for this save: the wait for
         # the previous one plus the device→host snapshot.
         with _trace.span("save_stall", "ckpt", step=step):
-            self.wait()  # one in-flight save at a time; surfaces errors
-            host_tree = snapshot_to_host(tree)
+            with _trace.span("save_wait", "ckpt", step=step):
+                self.wait()  # one in-flight save at a time; surfaces errors
+            with _trace.span("snapshot", "ckpt", step=step) as sp:
+                host_tree = snapshot_to_host(tree)
+                sp.add(bytes=sum(getattr(x, "nbytes", 0) for x in
+                                 jax.tree_util.tree_leaves(host_tree)))
         use_delta = self.delta if delta is None else bool(delta)
 
         def _write() -> None:

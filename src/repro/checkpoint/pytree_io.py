@@ -866,17 +866,29 @@ def _finalize_leaf(leaf: Dict[str, Any]):
         full = leaf["units"][0].arr.view(dtype).reshape(shape)
         if leaf["sharding"] is None:
             return full
-        arrays = [jax.device_put(full[index], device)
-                  for device, index in leaf["per_device"]]
-        return jax.make_array_from_single_device_arrays(
-            shape, leaf["sharding"], arrays)
-    arrays = [
-        jax.device_put(
-            leaf["units"][ui].arr.view(dtype)
-            .reshape(leaf["units"][ui].shard_shape), device)
-        for device, ui in leaf["per_device"]]
-    return jax.make_array_from_single_device_arrays(
-        shape, leaf["sharding"], arrays)
+        return _place(leaf["name"], shape, leaf["sharding"],
+                      ((full[index], device)
+                       for device, index in leaf["per_device"]))
+    units = leaf["units"]
+    return _place(leaf["name"], shape, leaf["sharding"],
+                  ((units[ui].arr.view(dtype).reshape(units[ui].shard_shape),
+                    device) for device, ui in leaf["per_device"]))
+
+
+def _place(name: str, shape, sharding, pieces):
+    """Host → device: put each ``(host array, device)`` piece on its
+    device and assemble the global array — one ``ckpt.place`` span with
+    the bytes sent to the devices."""
+    c = _trace.collector()
+    t0 = 0 if c is None else c.now()
+    arrays, nbytes = [], 0
+    for arr, device in pieces:
+        arrays.append(jax.device_put(arr, device))
+        nbytes += arr.nbytes
+    out = jax.make_array_from_single_device_arrays(shape, sharding, arrays)
+    if c is not None:
+        c.end("place", "ckpt", t0, {"leaf": name, "bytes": nbytes})
+    return out
 
 
 def _fill_joined(chunks: List[bytes], arr: np.ndarray, spec_) -> None:
@@ -978,8 +990,8 @@ def _read_leaf_to_target(r: ScdaReader, hdr, spec_, target):
             shard_arrays[key] = _read_shard(r, spec_, index, shape, dtype)
         per_device.append((device, shard_arrays[key]))
     r.skip_data()
-    arrays = [jax.device_put(arr, device) for device, arr in per_device]
-    return jax.make_array_from_single_device_arrays(shape, sharding, arrays)
+    return _place(spec_["name"], shape, sharding,
+                  ((arr, device) for device, arr in per_device))
 
 
 def _index_key(index, shape) -> Tuple:

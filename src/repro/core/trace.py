@@ -6,8 +6,8 @@ commit); this module is the one place they all report to, so a save or
 restore can be profiled per stage instead of bisected.  Three sinks:
 
 * **In-memory metrics** — :class:`Metrics` aggregates counters and
-  latency histograms; ``Metrics.snapshot()`` returns a plain dict
-  (``scdatool verify --timing`` and the benchmark harness read it).
+  per-stage latency counts and totals; ``Metrics.snapshot()`` returns a
+  plain dict (``scdatool verify --timing`` reads it).
 * **Chrome ``trace_event`` JSON** — every span becomes a complete
   ("X") event with real thread ids, so the codec/writeback/prefetch
   pools show up as separate tracks in ``chrome://tracing`` / Perfetto.
@@ -28,6 +28,19 @@ trace at process exit (and on :func:`flush`).  Programmatic use:
 Tracing never perturbs bytes: instrumented code paths are fuzzed
 byte-identical to untraced runs by ``tests/test_trace.py``.
 
+**One clock with the device profiler.**  Spans are stamped with
+``time.perf_counter_ns``; a ``jax.profiler`` trace (``.xplane.pb``) runs
+on the profiler's session clock.  Where JAX is already imported, an
+active collector pins the two together with clock anchors: a
+``jax.profiler.TraceAnnotation`` named :data:`CLOCK_ANCHOR` whose
+``t_ns`` stat is the collector's own timestamp, emitted when the
+collector is installed and then at most once a second while it records.
+:func:`clock_anchors` reads them back from a trace and
+:func:`to_trace_clock` maps a span's timestamps onto the trace's clock,
+so every idle stretch of a device can be set beside the spans open in
+it.  Without JAX imported nothing is emitted (``core`` never imports
+JAX itself).
+
 :func:`warn` is the single user-facing warning channel (degraded reads,
 stale-lock takeover): logging-backed (logger ``repro.scda`` — capture
 it with ``caplog`` in tests; without handlers it still lands on stderr
@@ -37,12 +50,14 @@ counted in the active collector's metrics.
 from __future__ import annotations
 
 import atexit
+import bisect
 import json
 import logging
 import os
+import sys
 import threading
 import time
-from typing import Any, Dict, Iterator, List, Optional, Tuple
+from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
 
 #: ``REPRO_SCDA_TRACE``: ``mem``/``1`` = collect in memory; any other
 #: value = also export Chrome trace JSON to that path at process exit.
@@ -51,6 +66,14 @@ TRACE_ENV = "REPRO_SCDA_TRACE"
 #: Event cap per collector — beyond it events drop (counted), metrics
 #: keep aggregating.  A full sharded+parity save is ~10k events.
 DEFAULT_MAX_EVENTS = 1_000_000
+
+#: Name of the profiler annotation that pins the collector's clock to a
+#: ``jax.profiler`` trace; its ``t_ns`` stat is ``time.perf_counter_ns``.
+CLOCK_ANCHOR = "scda.clock"
+
+#: Least spacing of the clock anchors a recording collector emits (ns):
+#: enough to follow the drift between the two clocks over a long window.
+ANCHOR_EVERY_NS = 1_000_000_000
 
 logger = logging.getLogger("repro.scda")
 
@@ -63,7 +86,7 @@ _atexit_registered = False
 # --------------------------------------------------------------------------
 
 class Metrics:
-    """Aggregated counters and log2-bucket latency histograms.
+    """Aggregated counters and per-name latency totals.
 
     Thread-safe; update cost is one lock + two dict ops, which is noise
     next to the syscalls being measured.  ``snapshot()`` is the read
@@ -73,7 +96,7 @@ class Metrics:
     def __init__(self) -> None:
         self._lock = threading.Lock()
         self._counters: Dict[str, int] = {}
-        # name -> [count, total, min, max, {bucket: count}] (µs values)
+        # name -> [count, total] (µs values)
         self._hists: Dict[str, list] = {}
 
     def count(self, name: str, n: int = 1) -> None:
@@ -81,60 +104,30 @@ class Metrics:
             self._counters[name] = self._counters.get(name, 0) + n
 
     def observe(self, name: str, value_us: float) -> None:
-        """Record one latency/size observation (microseconds by
-        convention for ``*.us`` names)."""
+        """Record one latency observation (microseconds by convention
+        for ``*.us`` names): its count and total."""
         with self._lock:
             h = self._hists.get(name)
             if h is None:
-                h = [0, 0.0, value_us, value_us, {}]
-                self._hists[name] = h
-            h[0] += 1
-            h[1] += value_us
-            if value_us < h[2]:
-                h[2] = value_us
-            if value_us > h[3]:
-                h[3] = value_us
-            b = max(0, int(value_us)).bit_length()
-            h[4][b] = h[4].get(b, 0) + 1
+                self._hists[name] = [1, value_us]
+            else:
+                h[0] += 1
+                h[1] += value_us
 
     def get(self, name: str) -> int:
         with self._lock:
             return self._counters.get(name, 0)
 
     def snapshot(self) -> Dict[str, Any]:
-        """``{"counters": {...}, "histograms": {name: {count, total_us,
-        mean_us, min_us, max_us, p50_us, p99_us}}}`` — a stable plain
-        dict copy."""
+        """``{"counters": {...}, "histograms": {name: {count,
+        total_us}}}`` — a stable plain dict copy."""
         with self._lock:
             counters = dict(self._counters)
-            hists = {k: (h[0], h[1], h[2], h[3], dict(h[4]))
-                     for k, h in self._hists.items()}
-        out_h: Dict[str, Any] = {}
-        for name, (count, total, mn, mx, buckets) in hists.items():
-            out_h[name] = {
-                "count": count,
-                "total_us": round(total, 3),
-                "mean_us": round(total / count, 3) if count else 0.0,
-                "min_us": round(mn, 3),
-                "max_us": round(mx, 3),
-                "p50_us": _bucket_quantile(buckets, count, 0.50),
-                "p99_us": _bucket_quantile(buckets, count, 0.99),
-            }
-        return {"counters": counters, "histograms": out_h}
-
-
-def _bucket_quantile(buckets: Dict[int, int], count: int,
-                     q: float) -> float:
-    """Upper bound of the log2 bucket holding quantile ``q`` (µs)."""
-    if not count:
-        return 0.0
-    want = max(1, int(count * q))
-    seen = 0
-    for b in sorted(buckets):
-        seen += buckets[b]
-        if seen >= want:
-            return float(1 << b)
-    return float(1 << max(buckets))
+            hists = {k: (h[0], h[1]) for k, h in self._hists.items()}
+        return {"counters": counters,
+                "histograms": {name: {"count": count,
+                                      "total_us": round(total, 3)}
+                               for name, (count, total) in hists.items()}}
 
 
 # --------------------------------------------------------------------------
@@ -190,8 +183,19 @@ class TraceCollector:
         self._epoch_ns = time.perf_counter_ns()
         self._commit_base: Dict[str, int] = {}
         self._commit_lock = threading.Lock()
+        self._next_anchor = 0
 
     # -- emission ----------------------------------------------------------
+
+    def _anchor(self) -> None:
+        """Emit a clock anchor now (see the module doc); a no-op until
+        something has imported JAX."""
+        t = time.perf_counter_ns()
+        self._next_anchor = t + ANCHOR_EVERY_NS
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        if profiler is not None:
+            with profiler.TraceAnnotation(CLOCK_ANCHOR, t_ns=t):
+                pass
 
     @staticmethod
     def now() -> int:
@@ -220,6 +224,8 @@ class TraceCollector:
             if b:
                 m.count(key + ".bytes", int(b))
         self._emit(name, cat, "X", t0, t1 - t0, args)
+        if t1 >= self._next_anchor:
+            self._anchor()
 
     def span(self, name: str, cat: str = "ckpt",
              **args: Any) -> _Span:
@@ -241,6 +247,8 @@ class TraceCollector:
             m.count(f"io.{op}.errors")
             args["error"] = error
         self._emit(op, "io", "X", t0, t1 - t0, args)
+        if t1 >= self._next_anchor:
+            self._anchor()
 
     def event(self, name: str, cat: str = "ckpt", **args: Any) -> None:
         """Instant event (lifecycle marks: commit, takeover, …)."""
@@ -272,7 +280,8 @@ class TraceCollector:
             events.append(ev)
         doc = {"traceEvents": events, "displayTimeUnit": "ms",
                "otherData": {"tool": "repro-scda",
-                             "dropped_events": self._dropped}}
+                             "dropped_events": self._dropped,
+                             "epoch_ns": self._epoch_ns}}
         return doc
 
     def export(self, path: Optional[str] = None) -> str:
@@ -336,6 +345,7 @@ def install(c: Optional["TraceCollector"] = None) -> "TraceCollector":
     if c is None:
         c = TraceCollector()
     _collector = c
+    c._anchor()
     return c
 
 
@@ -379,6 +389,7 @@ class scoped:
         global _collector
         self._prev = _collector
         _collector = self.collector
+        self.collector._anchor()
         return self.collector
 
     def __exit__(self, exc_type, exc, tb) -> None:
@@ -420,6 +431,46 @@ def event(name: str, cat: str = "ckpt", **args: Any) -> None:
     c = collector()
     if c is not None:
         c.event(name, cat, **args)
+
+
+# --------------------------------------------------------------------------
+# One clock with a jax.profiler trace
+# --------------------------------------------------------------------------
+
+def clock_anchors(xplane_path: str) -> List[Tuple[int, float]]:
+    """``(collector ns, trace ns)`` of every clock anchor in a
+    ``jax.profiler`` trace file (``.xplane.pb``), in order.  Imports
+    JAX to read it."""
+    from jax.profiler import ProfileData
+    out: List[Tuple[int, float]] = []
+    for plane in ProfileData.from_file(xplane_path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name == CLOCK_ANCHOR:
+                    t = dict(e.stats).get("t_ns")
+                    if t is not None:
+                        out.append((int(t), float(e.start_ns)))
+    return sorted(out)
+
+
+def to_trace_clock(ts_ns: float,
+                   anchors: Sequence[Tuple[float, float]]) -> float:
+    """A collector timestamp (``now()`` ns) on the clock of the trace
+    that holds ``anchors`` (:func:`clock_anchors`): interpolated between
+    the two neighbouring anchors, which follows the drift between the
+    clocks, and shifted by the nearest anchor's offset outside them.
+    With each anchor's pair swapped it maps trace time back."""
+    if not anchors:
+        raise ValueError("no clock anchors")
+    i = bisect.bisect_right(anchors, ts_ns, key=lambda a: a[0])
+    if 0 < i < len(anchors):
+        (p0, t0), (p1, t1) = anchors[i - 1], anchors[i]
+        if p1 > p0:
+            return t0 + (ts_ns - p0) * (t1 - t0) / (p1 - p0)
+    p, t = anchors[min(max(i - 1, 0), len(anchors) - 1)]
+    return t + (ts_ns - p)
 
 
 # --------------------------------------------------------------------------

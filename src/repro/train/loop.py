@@ -6,6 +6,15 @@ and an elastic resize are the same code path (the scda serial-equivalence
 guarantee is what makes the third case trivial).  Checkpoint failures are
 caught and logged — the paper's §A.6 "file errors should never crash the
 simulation" — while training continues.
+
+With a ``repro.core.trace`` collector active, each step records
+``train.batch`` (the next batch), ``train.step`` (the step call: its
+first call holds the trace, the compile and the transfer of its
+arguments), ``train.loss_read`` (the wait for the step's loss) and a
+``train.hook`` span around each hook call, named by its ``hook``
+argument.  ``train.compile`` spans are JAX's own compile-stage durations
+(tracing, lowering, compile or persistent-cache load), each ending where
+JAX reported it, on the thread that compiled.
 """
 from __future__ import annotations
 
@@ -19,6 +28,7 @@ import jax
 
 from repro.checkpoint import CheckpointManager
 from repro.configs.base import ModelConfig
+from repro.core import trace as _trace
 from repro.data.pipeline import DataConfig, SyntheticTokens
 from repro.distributed import sharding as sh
 from repro.launch import specs
@@ -27,6 +37,43 @@ from repro.optim import adamw
 from repro.train.step import make_train_step
 
 log = logging.getLogger("repro.train")
+
+#: Prefix of the ``jax.monitoring`` duration events that become
+#: ``train.compile`` spans.
+COMPILE_EVENTS = "/jax/core/compile/"
+_compiles_watched = False
+
+
+def _record_compile(event: str, secs: float, **kw: Any) -> None:
+    """``jax.monitoring`` listener: a compile-stage duration as a
+    ``train.compile`` span ending now, while a collector is active."""
+    c = _trace.collector()
+    if c is None or not event.startswith(COMPILE_EVENTS):
+        return
+    c.end("compile", "train", c.now() - int(secs * 1e9),
+          {"event": event, "fun": kw.get("fun_name")})
+
+
+def _watch_compiles() -> None:
+    global _compiles_watched
+    if not _compiles_watched:
+        _compiles_watched = True
+        jax.monitoring.register_event_duration_secs_listener(_record_compile)
+
+
+def _hook(hooks: Dict[str, Callable], name: str, tc, *args: Any) -> Any:
+    """Call hook ``name`` if there is one: a ``train.hook`` span when a
+    collector ``tc`` is active."""
+    fn = hooks.get(name)
+    if fn is None:
+        return None
+    if tc is None:
+        return fn(*args)
+    t0 = tc.now()
+    try:
+        return fn(*args)
+    finally:
+        tc.end("hook", "train", t0, {"hook": name})
 
 
 @dataclasses.dataclass
@@ -117,34 +164,44 @@ def _train(cfg, loop, opt_cfg, data, mesh, seq_len, global_batch, hooks):
         init_fn = jax.jit(init_fn, out_shardings=jax.tree_util.tree_map(
             lambda a: a.sharding, like))
 
+    _watch_compiles()
     mgr = CheckpointManager(loop.ckpt_dir, keep=loop.ckpt_keep,
                             compressed=loop.ckpt_compressed)
     state, start_step = mgr.restore_or_init(init_fn, like=like)
     if start_step >= 0:
         log.info("resumed from checkpoint at step %d", start_step)
-    if "on_start" in hooks:
-        hooks["on_start"](start_step, state)
+    _hook(hooks, "on_start", _trace.collector(), start_step, state)
     metrics: Dict[str, Any] = {}
     losses = []
     t0 = time.time()
     for step in range(start_step + 1, loop.total_steps):
+        # Looked up once a step: a collector may be installed mid-run.
+        tc = _trace.collector()
+        ts = 0 if tc is None else tc.now()
         batch = data.sharded_batch(step, mesh)
+        if tc is not None:
+            tc.end("batch", "train", ts, {"step": step})
+            ts = tc.now()
         params, opt, metrics = step_fn(state["params"], state["opt"], batch)
         state = {"params": params, "opt": opt}
+        if tc is not None:
+            tc.end("step", "train", ts, {"step": step})
+            ts = tc.now()
         losses.append(float(metrics["loss"]))
+        if tc is not None:
+            tc.end("loss_read", "train", ts, {"step": step})
         if step % loop.log_every == 0 or step == loop.total_steps - 1:
             log.info("step %d loss %.4f gnorm %.3f lr %.2e (%.2fs)",
                      step, float(metrics["loss"]),
                      float(metrics["grad_norm"]), float(metrics["lr"]),
                      time.time() - t0)
-        if "on_step" in hooks:
-            hooks["on_step"](step, state, metrics)
+        _hook(hooks, "on_step", tc, step, state, metrics)
         if loop.ckpt_every and step % loop.ckpt_every == 0 and step > 0:
             try:
                 mgr.save(step, state)
             except Exception as e:  # noqa: BLE001 — never crash the job
                 log.error("checkpoint save failed (continuing): %s", e)
-        if "should_die" in hooks and hooks["should_die"](step):
+        if _hook(hooks, "should_die", tc, step):
             # failure-injection hook used by tests/examples
             mgr.wait()
             raise SystemExit(f"injected failure at step {step}")

@@ -59,10 +59,7 @@ def test_metrics_counters_and_histograms():
     assert snap["counters"]["io.pwrite.calls"] == 3
     assert snap["counters"]["io.pwrite.bytes"] == 4096
     h = snap["histograms"]["io.pwrite.us"]
-    assert h["count"] == 4
-    assert h["min_us"] == 1.0 and h["max_us"] == 1000.0
-    assert h["mean_us"] == pytest.approx(1111.0 / 4)
-    assert h["p50_us"] <= h["p99_us"]
+    assert h == {"count": 4, "total_us": pytest.approx(1111.0)}
     assert json.dumps(snap)  # plain-dict, JSON-able as-is
 
 
@@ -100,6 +97,7 @@ def test_quiet_path_is_cheap():
     # The disabled guard is one global load + one environ lookup; a
     # generous absolute bound catches an accidental allocation or I/O
     # on the quiet path without being timing-flaky.
+    from repro.train import loop
     assert trace.collector() is None
     n = 50_000
     t0 = time.perf_counter()
@@ -107,6 +105,20 @@ def test_quiet_path_is_cheap():
         trace.collector()
     per_call_us = (time.perf_counter() - t0) * 1e6 / n
     assert per_call_us < 25.0
+    # The train loop's per-step calls on the quiet path: one collector
+    # lookup, the hook calls, and the compile listener's bail-out.
+    hooks = {"on_step": lambda step, state, metrics: None,
+             "should_die": lambda step: False}
+    t0 = time.perf_counter()
+    for step in range(n):
+        tc = trace.collector()
+        loop._hook(hooks, "on_step", tc, step, None, None)
+        loop._hook(hooks, "should_die", tc, step)
+        loop._hook(hooks, "on_start", tc, step, None)
+        loop._record_compile(loop.COMPILE_EVENTS + "x", 1e-3)
+    per_step_us = (time.perf_counter() - t0) * 1e6 / n
+    assert per_step_us < 25.0
+    assert trace.collector() is None
 
 
 def test_scoped_installs_and_restores(tmp_path):
@@ -367,3 +379,166 @@ def test_save_trace_kwarg_exports(tmp_path):
     tc = trace.TraceCollector()
     pytree_io.save(path, _tree(), step=5, trace=tc)
     assert tc.metrics.get("ckpt.save.calls") == 1
+
+
+# ------------------------------------------------ one clock with jax ----
+
+def _xplane(d):
+    import glob
+    return glob.glob(os.path.join(str(d), "**", "*.xplane.pb"),
+                     recursive=True)[0]
+
+
+def test_spans_land_on_the_profiler_clock(tmp_path, monkeypatch):
+    """A program span and a TraceAnnotation around the same region map
+    onto each other within 1 ms through the collector's clock anchors,
+    on a real (CPU) profiler trace."""
+    import jax
+    monkeypatch.setattr(trace, "ANCHOR_EVERY_NS", 20_000_000)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        tc = trace.install(trace.TraceCollector())
+        for i in range(4):
+            with jax.profiler.TraceAnnotation(f"region{i}"):
+                with tc.span(f"region{i}", "test"):
+                    time.sleep(0.03)
+    finally:
+        trace.uninstall()
+        jax.profiler.stop_trace()
+    path = _xplane(tmp_path)
+    anchors = trace.clock_anchors(path)
+    assert len(anchors) >= 3  # install, then from end() as time passes
+    assert all(a[0] < b[0] and a[1] < b[1]
+               for a, b in zip(anchors, anchors[1:]))
+    from jax.profiler import ProfileData
+    marks = {e.name: (e.start_ns, e.start_ns + e.duration_ns)
+             for p in ProfileData.from_file(path).planes
+             for line in p.lines for e in line.events
+             if e.name.startswith("region")}
+    # The Chrome document's route: ts (µs) from the collector's epoch.
+    doc = tc.chrome()
+    epoch = doc["otherData"]["epoch_ns"]
+    spans = {ev["name"]: (epoch + ev["ts"] * 1000,
+                          epoch + (ev["ts"] + ev["dur"]) * 1000)
+             for ev in doc["traceEvents"] if ev["cat"] == "test"}
+    assert set(spans) == set(marks) == {f"region{i}" for i in range(4)}
+    for name, (t0, t1) in spans.items():
+        a, b = marks[name]
+        assert abs(trace.to_trace_clock(t0, anchors) - a) < 1e6
+        assert abs(trace.to_trace_clock(t1, anchors) - b) < 1e6
+
+
+def test_clock_mapping_interpolates_between_anchors():
+    anchors = [(1000, 10.0), (2000, 1010.5), (4000, 3010.5)]
+    assert trace.to_trace_clock(1000, anchors) == 10.0
+    assert trace.to_trace_clock(1500, anchors) == 510.25
+    assert trace.to_trace_clock(3000, anchors) == 2010.5
+    # Outside the anchors: the nearest anchor's offset.
+    assert trace.to_trace_clock(500, anchors) == -490.0
+    assert trace.to_trace_clock(5000, anchors) == 4010.5
+    back = [(t, p) for p, t in anchors]
+    for ts in (700, 1000, 1234, 2500, 4000, 4500):
+        assert trace.to_trace_clock(
+            trace.to_trace_clock(ts, anchors), back) == pytest.approx(ts)
+    with pytest.raises(ValueError):
+        trace.to_trace_clock(1, [])
+
+
+def test_no_anchor_without_jax_imported(tmp_path, monkeypatch):
+    """``repro.core.trace`` never imports JAX, and with JAX absent from
+    ``sys.modules`` an active collector emits no anchor."""
+    import subprocess
+    import sys
+    import jax
+    code = ("import sys; from repro.core import trace; "
+            "c = trace.install(); c.end('s', 'test', c.now()); "
+            "c.io_op('pwrite', 'f', 0, 1, c.now()); trace.uninstall(); "
+            "assert 'jax' not in sys.modules, 'core imported jax'")
+    env = dict(os.environ, PYTHONPATH=os.pathsep.join(sys.path))
+    subprocess.run([sys.executable, "-c", code], check=True, env=env,
+                   timeout=120)
+    monkeypatch.setattr(trace, "ANCHOR_EVERY_NS", 0)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with monkeypatch.context() as m:
+            m.delitem(sys.modules, "jax")
+            tc = trace.install(trace.TraceCollector())
+            for _ in range(3):
+                with tc.span("s", "test"):
+                    pass
+            trace.uninstall()
+    finally:
+        jax.profiler.stop_trace()
+    assert trace.clock_anchors(_xplane(tmp_path)) == []
+
+
+# --------------------------------------------- spans of the train loop ----
+
+def test_train_loop_spans(tmp_path):
+    """Under a collector, ``train()`` records one batch/step/loss_read
+    span per step and one hook span per hook call, spans nest per
+    thread, and JAX's compile stages fall in the first step only."""
+    from repro.configs.base import ModelConfig
+    from repro.train.loop import TrainLoopConfig, train
+    cfg = ModelConfig(name="tiny", family="dense", n_layers=1, d_model=32,
+                      vocab=64, n_heads=2, n_kv_heads=1, head_dim=16,
+                      d_ff=64, mlp_type="swiglu")
+    loop_cfg = TrainLoopConfig(total_steps=4, ckpt_every=2,
+                               ckpt_dir=str(tmp_path / "ck"), ckpt_keep=1,
+                               log_every=1 << 30)
+    hooks = {"on_start": lambda s, st: None,
+             "on_step": lambda s, st, m: None,
+             "should_die": lambda s: False}
+    tc = trace.TraceCollector()
+    with trace.scoped(tc):
+        out = train(cfg, loop_cfg, seq_len=8, global_batch=2, hooks=hooks)
+    assert len(out["losses"]) == 4
+    events = tc.chrome()["traceEvents"]
+    _spans_nest(events)
+    loop_spans = [e for e in events if e["ph"] == "X" and e["cat"] == "train"]
+    for name in ("batch", "step", "loss_read"):
+        got = [e["args"]["step"] for e in loop_spans if e["name"] == name]
+        assert got == [0, 1, 2, 3], name
+    hooks_seen = [e["args"]["hook"] for e in loop_spans if e["name"] == "hook"]
+    assert hooks_seen == ["on_start"] + ["on_step", "should_die"] * 4
+    steps = sorted((e["ts"], e["ts"] + e["dur"], e["args"]["step"])
+                   for e in loop_spans if e["name"] == "step")
+    compiles = [e for e in loop_spans if e["name"] == "compile"]
+    assert all(e["args"]["event"].startswith("/jax/core/compile/")
+               for e in compiles)
+    inside = {s for a, b, s in steps for e in compiles
+              if a <= e["ts"] and e["ts"] + e["dur"] <= b}
+    assert inside == {0}
+    main = {e["tid"] for e in loop_spans if e["name"] == "step"}
+    assert len(main) == 1
+    assert {e["tid"] for e in compiles} <= main
+
+
+def test_save_stall_holds_wait_and_snapshot(tmp_path):
+    """``save`` records ``ckpt.save_wait`` and ``ckpt.snapshot`` inside
+    ``ckpt.save_stall``; the snapshot's bytes are the tree's."""
+    tree = _tree()
+    tc = trace.TraceCollector()
+    with trace.scoped(tc):
+        with CheckpointManager(str(tmp_path / "ck"), keep=2) as mgr:
+            mgr.save(1, tree)
+            mgr.save(2, _tree(seed=1))
+            mgr.wait()
+    events = tc.chrome()["traceEvents"]
+    _spans_nest(events)
+    ck = [e for e in events if e["ph"] == "X" and e["cat"] == "ckpt"]
+    stalls = [e for e in ck if e["name"] == "save_stall"]
+    assert [e["args"]["step"] for e in stalls] == [1, 2]
+    for stall in stalls:
+        a, b = stall["ts"], stall["ts"] + stall["dur"]
+        tid = stall["tid"]
+        parts = [e["name"] for e in ck if e["tid"] == tid
+                 and e["name"] in ("save_wait", "snapshot")
+                 and a <= e["ts"] and e["ts"] + e["dur"] <= b
+                 and e["args"]["step"] == stall["args"]["step"]]
+        assert parts == ["save_wait", "snapshot"]
+    nbytes = sum(v.nbytes for v in tree.values())
+    snaps = [e["args"]["bytes"] for e in ck if e["name"] == "snapshot"]
+    assert snaps[0] == nbytes
+    assert tc.metrics.get("ckpt.snapshot.bytes") == sum(snaps)
+    assert tc.metrics.get("ckpt.save_wait.calls") == 2
