@@ -39,7 +39,7 @@ def _time(fn, reps):
 def run(quick=False):
     rows = []
     n_frag = 256 if quick else 1024
-    # Above io_backend._JOIN_SMALL so the coalesced strategy actually
+    # Above io_backend.JOIN_SMALL so the coalesced strategy actually
     # exercises the zero-copy multi-iovec pwritev branch (small fragments
     # would be user-space pre-joined and measure a plain pwrite).
     frag_bytes = 16384

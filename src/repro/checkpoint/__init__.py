@@ -22,7 +22,8 @@ from repro.checkpoint.sharding import (save_sharded, read_sharded_manifest,
 from repro.checkpoint.pytree_io import (save, restore, restore_leaf,
                                         read_manifest, flatten_named,
                                         leaf_name, DEFAULT_CHUNK_BYTES)
-from repro.checkpoint.manager import CheckpointManager, snapshot_to_host
+from repro.checkpoint.manager import CheckpointManager
+from repro.checkpoint.snapshot import snapshot_to_host
 
 __all__ = [
     "shard_runs", "chunk_sizes", "chunks_for_runs", "runs_cover_exactly",

@@ -36,6 +36,34 @@ def _normalize(global_shape: Sequence[int], index) -> Tuple[List[int], List[int]
     return starts, extents
 
 
+def _geometry(global_shape: Sequence[int], index):
+    """``(starts, extents, k)`` of a shard: dims after ``k`` are spanned
+    whole, so one run covers dim ``k``'s extent times the trailing dims.
+    ``None`` for an empty shard."""
+    nd = len(global_shape)
+    if index is None or len(index) == 0:
+        index = tuple(slice(0, d) for d in global_shape)
+    starts, extents = _normalize(global_shape, index)
+    if any(e == 0 for e in extents) or any(d == 0 for d in global_shape):
+        return None
+    # Largest full suffix: dims j > k with the shard spanning the whole dim.
+    k = nd - 1
+    while k >= 0 and starts[k] == 0 and extents[k] == global_shape[k]:
+        k -= 1
+    return starts, extents, k
+
+
+def run_bytes(global_shape: Sequence[int], index, itemsize: int) -> int:
+    """Length of each of :func:`shard_runs`' runs (0 for an empty shard)."""
+    if not len(global_shape):
+        return itemsize
+    g = _geometry(list(global_shape), index)
+    if g is None:
+        return 0
+    _, extents, k = g
+    return math.prod(extents[max(k, 0):]) * itemsize
+
+
 def shard_runs(global_shape: Sequence[int], index,
                itemsize: int) -> List[Run]:
     """Contiguous row-major runs of the shard ``index`` of a global tensor.
@@ -47,20 +75,13 @@ def shard_runs(global_shape: Sequence[int], index,
     nd = len(global_shape)
     if nd == 0:  # scalar
         return [(0, 0, itemsize)]
-    if index is None or len(index) == 0:
-        index = tuple(slice(0, d) for d in global_shape)
-    starts, extents = _normalize(global_shape, index)
-    if any(e == 0 for e in extents) or any(d == 0 for d in global_shape):
+    g = _geometry(global_shape, index)
+    if g is None:
         return []
-    # Largest full suffix: dims j > k with the shard spanning the whole dim.
-    k = nd - 1
-    while k >= 0 and starts[k] == 0 and extents[k] == global_shape[k]:
-        k -= 1
+    starts, extents, k = g
     if k < 0:  # shard is the whole tensor
         return [(0, 0, math.prod(global_shape) * itemsize)]
-    # One run covers dim k's extent times all trailing (full) dims.
-    trailing = math.prod(global_shape[k + 1:])
-    run_bytes = extents[k] * trailing * itemsize
+    run = math.prod(extents[k:]) * itemsize
     # Global row-major element strides.
     strides = [0] * nd
     acc = 1
@@ -72,8 +93,8 @@ def shard_runs(global_shape: Sequence[int], index,
     for multi in itertools.product(*(range(e) for e in extents[:k])):
         gelem = sum((starts[j] + multi[j]) * strides[j] for j in range(k))
         gelem += starts[k] * strides[k]
-        runs.append((gelem * itemsize, local, run_bytes))
-        local += run_bytes
+        runs.append((gelem * itemsize, local, run))
+        local += run
     return runs
 
 

@@ -36,13 +36,13 @@ import threading
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
-import numpy as np
 
 from repro.checkpoint import delta as _delta
 from repro.checkpoint import pytree_io
 from repro.checkpoint import redundancy as _red
 from repro.checkpoint import sharding as _sharding
 from repro.checkpoint import manifest as _mf
+from repro.checkpoint.snapshot import snapshot_to_host
 from repro.core import ScdaError
 from repro.core import trace as _trace
 from repro.core.comm import Communicator, SerialComm
@@ -63,20 +63,6 @@ LOCK_TTL_SECONDS = 3600.0
 
 def _ckpt_name(step: int) -> str:
     return f"step_{step:010d}.scda"
-
-
-def snapshot_to_host(tree):
-    """Synchronous device→host copy preserving shape/dtype (per shard).
-
-    For single-process jax.Arrays the result is plain numpy (canonical
-    layout); the background writer then never touches device state, so
-    training can overwrite donated buffers immediately.
-    """
-    def _snap(x):
-        if isinstance(x, jax.Array):
-            return np.asarray(x)
-        return x
-    return jax.tree_util.tree_map(_snap, tree)
 
 
 class CheckpointManager:

@@ -40,11 +40,13 @@ import jax
 import numpy as np
 
 from repro.checkpoint import layout, manifest as mf
+from repro.checkpoint.snapshot import HostShards, leaf_to_host
 from repro.core import ScdaError, ScdaErrorCode, partition
 from repro.core import trace as _trace
 from repro.core.comm import Communicator, SerialComm
 from repro.core.index import ScdaIndex
-from repro.core.io_backend import prefetch_window, write_pipeline_window
+from repro.core.io_backend import (JOIN_SMALL, prefetch_window,
+                                   write_pipeline_window)
 from repro.core.pipeline import ReadItem, run_pipeline
 from repro.core.reader import ScdaReader, fopen_read
 from repro.core.writer import fopen_write
@@ -135,7 +137,8 @@ def flatten_named(tree) -> Tuple[List[Tuple[str, Any]], Any]:
 
 
 def _is_array(x) -> bool:
-    return isinstance(x, (jax.Array, np.ndarray)) and np.ndim(x) is not None
+    return isinstance(x, (jax.Array, np.ndarray, HostShards)) \
+        and np.ndim(x) is not None
 
 
 # --------------------------------------------------------------------------
@@ -150,27 +153,45 @@ def _byte_view(host: np.ndarray) -> memoryview:
     return memoryview(np.ascontiguousarray(host).reshape(-1).view(np.uint8))
 
 
+def _short_runs(arr: HostShards) -> bool:
+    """Some shard of ``arr`` splits into many runs of at most
+    :data:`JOIN_SMALL` bytes (a column-sharded leaf's rows)."""
+    for index, host in arr.shards:
+        run = layout.run_bytes(arr.shape, index, arr.dtype.itemsize)
+        if 0 < run <= JOIN_SMALL and host.nbytes > run:
+            return True
+    return False
+
+
 def _owned_windows(arr, nbytes: int) -> List[Tuple[int, memoryview]]:
     """This process's deduplicated (byte_offset, buffer) windows of ``arr``.
 
-    For a jax.Array, every addressable shard with replica_id == 0 is owned
-    here; across all processes that tiles the canonical stream exactly once.
-    numpy arrays are treated as fully owned (callers pass them on rank 0 or
-    rely on identical replicated writes, which are byte-identical anyway).
+    A jax.Array is snapshotted first (:mod:`repro.checkpoint.snapshot`):
+    each addressable shard with replica_id == 0 is owned here, and across
+    all processes those tile the canonical stream exactly once.  A
+    :class:`HostShards` gives one window per contiguous run of each shard
+    buffer.  numpy arrays are treated as fully owned (callers pass them
+    on rank 0 or rely on identical replicated writes, which are
+    byte-identical anyway).
 
     A 2-D-sharded tensor's shards interleave in the canonical stream;
     ``ScdaWriter.write_array_windows`` sorts the windows and coalesces runs
     that are contiguous *across shards* into single vectored writes.
     """
-    windows: List[Tuple[int, memoryview]] = []
     if isinstance(arr, jax.Array):
-        for shard in arr.addressable_shards:
-            if shard.replica_id != 0:
-                continue
-            host = np.asarray(shard.data)
+        arr = leaf_to_host(arr)
+    if isinstance(arr, HostShards) and arr.complete and _short_runs(arr):
+        # The backend copies runs this short into joined buffers anyway
+        # (io_backend.JOIN_SMALL): one gather costs no more copying, and
+        # spares a window per run.
+        arr = np.asarray(arr)
+    windows: List[Tuple[int, memoryview]] = []
+    if isinstance(arr, HostShards):
+        itemsize = arr.dtype.itemsize
+        for index, host in arr.shards:
             buf = _byte_view(host)
-            for goff, loff, length in layout.shard_runs(
-                    arr.shape, shard.index, arr.dtype.itemsize):
+            for goff, loff, length in layout.shard_runs(arr.shape, index,
+                                                        itemsize):
                 windows.append((goff, buf[loff:loff + length]))
     else:
         host = np.asarray(arr)

@@ -134,7 +134,7 @@ except (AttributeError, ValueError, OSError):  # pragma: no cover
 #: space before the vectored write: copying a few KB costs less than the
 #: kernel's per-iovec-segment processing, while big payload views are
 #: always passed through zero-copy.
-_JOIN_SMALL = 8 << 10
+JOIN_SMALL = 8 << 10
 
 
 def as_byte_view(data: BytesLike) -> memoryview:
@@ -254,7 +254,7 @@ class FileBackend:
             v = _as_view(b)
             if not len(v):
                 continue
-            if len(v) <= _JOIN_SMALL:
+            if len(v) <= JOIN_SMALL:
                 small.append(v)
                 continue
             if small:  # join the run of small fragments, keep v zero-copy
