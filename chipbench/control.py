@@ -22,12 +22,13 @@ import time
 
 
 def readings(bench, cell_name, seed, variants=("fp8", "half_batch")):
-    from chipbench import check, reference
+    from chipbench import check
     from chipbench.data import DataConfig, SyntheticTokens
     cell = bench.cell(cell_name)
     config, traffic = bench.config(cell), bench.traffic(cell)
-    arch = reference.Arch.from_config(config)
-    adam = reference.Adam(**traffic["optimizer"])
+    ref = bench.reference(config)
+    arch = ref.Arch.from_config(config)
+    adam = ref.Adam(**traffic["optimizer"])
     seed = seed % (1 << 32)
     n = traffic["checked_steps"]
     steps = traffic["save_at"] + 2 if traffic["mix"] == "resume" else n
@@ -36,7 +37,7 @@ def readings(bench, cell_name, seed, variants=("fp8", "half_batch")):
                                       traffic["global_batch"], seed))
     batches = [data.global_batch_shard(s, 0, traffic["global_batch"])
                for s in range(steps)]
-    truth = reference.train(arch, adam, seed, batches, change_after=n)
+    truth = ref.train(arch, adam, seed, batches, change_after=n)
     # A resume cell compares the set-up's steps 0..save_at, then the first
     # step after each resume; a train cell its checked steps.
     m = steps - 1 if traffic["mix"] == "resume" else n
@@ -47,7 +48,7 @@ def readings(bench, cell_name, seed, variants=("fp8", "half_batch")):
         if x < check.NEGLIGIBLE_GRAD * floor)}
     for v in variants:
         kw = {"precision": "fp8"} if v == "fp8" else {"fault": v}
-        got = reference.train(arch, adam, seed, batches, change_after=n, **kw)
+        got = ref.train(arch, adam, seed, batches, change_after=n, **kw)
         g = check.gaps({**got, "losses": got["losses"][:m]},
                        {**truth, "losses": truth["losses"][:m]})
         if traffic["mix"] == "resume":

@@ -45,7 +45,7 @@ from typing import Any, Dict, List, Optional
 import jax
 import numpy as np
 
-from chipbench import check, reference
+from chipbench import check
 from chipbench.data import DataConfig, SyntheticTokens
 
 #: Bytes kept free on the checkpoint's file system beyond two states.
@@ -98,25 +98,6 @@ class Outcome:
     memory_peak_bytes: int
     #: readings behind the numbers, printed for whoever sets the limits
     details: Dict[str, Any] = dataclasses.field(default_factory=dict)
-
-
-def model_config(c: Dict[str, Any]):
-    """The program's ``ModelConfig`` for a configuration file."""
-    from repro.configs.base import ModelConfig
-    if c.get("hidden_act", "silu") != "silu":
-        raise ValueError("only SwiGLU (silu) feed-forwards are driven")
-    experts = c.get("num_local_experts", 0)
-    return ModelConfig(
-        name=c["name"], family="moe" if experts else "dense",
-        n_layers=c["num_hidden_layers"], d_model=c["hidden_size"],
-        vocab=c["vocab_size"], n_heads=c["num_attention_heads"],
-        n_kv_heads=c["num_key_value_heads"], head_dim=c["head_dim"],
-        qk_norm=c["qk_norm"], rope_base=float(c["rope_theta"]),
-        d_ff=c["intermediate_size"], mlp_type="swiglu",
-        n_experts=experts, experts_top_k=c.get("num_experts_per_tok", 0),
-        capacity_factor=c.get("moe_capacity_factor", 1.25),
-        tie_embeddings=c["tie_word_embeddings"],
-        norm_eps=float(c["rms_norm_eps"]), dtype=c["compute_dtype"])
 
 
 @contextlib.contextmanager
@@ -283,8 +264,9 @@ class _Capture:
     losses, the first gradient (from AdamW's first moment after step 0:
     ``mu_1 = (1 - b1) g``) and the parameters' change over the steps."""
 
-    def __init__(self, n: int, b1: float, rec: Records):
+    def __init__(self, n: int, b1: float, rec: Records, leaf_norms):
         self.n, self.b1, self.rec = n, b1, rec
+        self.leaf_norms = leaf_norms
         self.losses: List[float] = []
         self.grad_norms: Dict[str, float] = {}
         self.update_norms: Dict[str, float] = {}
@@ -309,7 +291,7 @@ class _Capture:
         self.losses.append(loss)
         if step == 0:
             def grads():
-                mu = reference.leaf_norms(state["opt"].mu)
+                mu = self.leaf_norms(state["opt"].mu)
                 self.grad_norms = {k: v / (1 - self.b1)
                                    for k, v in mu.items()}
             self.timed(grads)
@@ -330,21 +312,21 @@ class _Capture:
 
 class Run:
     """One run of one cell: ``config`` and ``traffic`` are the parsed
-    files, ``workdir`` a fixed directory inside the checkout."""
+    files, ``ref`` and ``program`` the modules the configuration names,
+    ``workdir`` a fixed directory inside the checkout."""
 
     def __init__(self, config: Dict[str, Any], traffic: Dict[str, Any],
-                 seed: int, seconds: float, trace: bool, workdir: str,
-                 devices=None):
-        self.config, self.traffic = config, traffic
+                 ref, program, seed: int, seconds: float, trace: bool,
+                 workdir: str, devices=None):
+        self.config, self.traffic, self.ref = config, traffic, ref
         self.seed = seed % (1 << 32)   # the program's PRNG key holds 32 bits
         self.seconds, self.workdir = seconds, workdir
         self.devices = devices or jax.devices()
         self.rec = Records()
         self.tracer = _Tracer(trace, os.path.join(workdir, "trace"))
-        self.cfg = model_config(config)
-        self.arch = reference.Arch.from_config(config)
-        o = traffic["optimizer"]
-        self.adam = reference.Adam(**o)
+        self.cfg = program.model_config(config)
+        self.arch = ref.Arch.from_config(config)
+        self.adam = ref.Adam(**traffic["optimizer"])
         self.ckpt_dir = os.path.join(workdir, "ckpt")
         self.managers: list = []
         self.spans: List[Dict[str, Any]] = []
@@ -403,9 +385,9 @@ class Run:
         batches = [self.data.global_batch_shard(s, 0,
                                                 self.traffic["global_batch"])
                    for s in range(steps)]
-        return reference.train(self.arch, self.adam, self.seed, batches,
-                               change_after=change_after,
-                               device=self.devices[0])
+        return self.ref.train(self.arch, self.adam, self.seed, batches,
+                              change_after=change_after,
+                              device=self.devices[0])
 
     # -- the mixes -------------------------------------------------------
     def run(self) -> Outcome:
@@ -428,7 +410,7 @@ class Run:
         tr, rec = self.traffic, self.rec
         n_check, every = tr["checked_steps"], tr["save_every"]
         last_step = n_check + tr["window_steps"] - 1
-        cap = _Capture(n_check, self.adam.b1, rec)
+        cap = _Capture(n_check, self.adam.b1, rec, self.ref.leaf_norms)
         saved_sums: Dict[int, Any] = {}
 
         def on_start(start_step, state):
@@ -499,7 +481,7 @@ class Run:
     def _run_resume(self) -> Outcome:
         tr, rec = self.traffic, self.rec
         n_check, at = tr["checked_steps"], tr["save_at"]
-        cap = _Capture(n_check, self.adam.b1, rec)
+        cap = _Capture(n_check, self.adam.b1, rec, self.ref.leaf_norms)
         want: Dict[str, Any] = {}
         setup_losses: Dict[int, float] = {}
 

@@ -5,16 +5,25 @@ Everything that belongs to one configuration, traffic mix or per-layer
 metric is a file of its own, found by name:
 
 * ``chipbench/configs/<config>.json``: the configuration's sizes (the
-  ``file`` of its entry in ``BENCHMARK.json``);
+  ``file`` of its entry in ``BENCHMARK.json``), and the two modules of
+  its architecture, each a file under ``chipbench/`` named by its path
+  from the root: ``"reference"``, the plain float32 reference
+  (``Arch.from_config``, ``Adam``, ``init_params``, ``leaf_norms``,
+  ``train`` with the control's ``precision="fp8"`` and
+  ``fault="half_batch"``, and ``train_flops_per_token``), which imports
+  nothing of the program, and ``"program"``, whose
+  ``model_config(config)`` gives the program's ``ModelConfig``;
 * ``chipbench/traffic/<traffic>.json``: the mix's parameters, read by
   ``drive.Run``;
 * ``chipbench/limits/<cell>.json``: the limit of each compared number;
 * ``chipbench/metrics/<metric>.py``: the reader of a per-layer metric.
 
-A new cell, configuration, mix or metric is new files and new entries.
+A new cell, configuration, architecture, mix or metric is new files and
+new entries.
 """
 from __future__ import annotations
 
+import hashlib
 import importlib.util
 import json
 import os
@@ -50,6 +59,14 @@ class Bench:
                 return json.loads((self.root / c["file"]).read_text())
         raise KeyError(f"no configuration {cell['config']!r}")
 
+    def reference(self, config: Dict[str, Any]):
+        """The module of the configuration's plain reference."""
+        return arch_module(config, "reference", self.root)
+
+    def program(self, config: Dict[str, Any]):
+        """The module that maps the configuration to the program's model."""
+        return arch_module(config, "program", self.root)
+
     def traffic(self, cell: Dict[str, Any]) -> Dict[str, Any]:
         return json.loads((self.root / "chipbench" / "traffic" /
                            f"{cell['traffic']}.json").read_text())
@@ -81,6 +98,32 @@ def reader(name: str, root: Path = ROOT):
     return mod
 
 
+def arch_module(config: Dict[str, Any], key: str, root: Path):
+    """The module of the file under ``chipbench/`` that a configuration
+    names under ``key``, loaded once a process."""
+    if key not in config:
+        raise KeyError(f"configuration {config.get('name')!r} names no "
+                       f"{key!r} module")
+    path = (root / config[key]).resolve()
+    if (root / "chipbench").resolve() not in path.parents:
+        raise ValueError(f"configuration {config.get('name')!r}: {key} "
+                         f"{config[key]!r} is not a file under chipbench/")
+    name = "chipbench._arch_" + hashlib.sha1(
+        str(path).encode()).hexdigest()[:16]
+    mod = sys.modules.get(name)
+    if mod is None:
+        spec = importlib.util.spec_from_file_location(name, path)
+        mod = importlib.util.module_from_spec(spec)
+        # A dataclass looks its module up in sys.modules while it is made.
+        sys.modules[name] = mod
+        try:
+            spec.loader.exec_module(mod)
+        except BaseException:
+            del sys.modules[name]
+            raise
+    return mod
+
+
 def peak(kind: str) -> Dict[str, Any]:
     table = json.loads((HERE / "peaks.json").read_text())
     if kind not in table:
@@ -105,7 +148,6 @@ class MetricInput:
     """What a per-layer reader reads from."""
 
     def __init__(self, run, outcome, device, peak_row, chips):
-        from chipbench import flops
         self.records = outcome.records
         self.spans = run.spans
         self.device = device
@@ -113,7 +155,7 @@ class MetricInput:
         self.chips = chips
         self.peak = peak_row
         self.tokens_per_step = outcome.records.tokens_per_step
-        self.flops_per_token = flops.train_flops_per_token(
+        self.flops_per_token = run.ref.train_flops_per_token(
             run.config, run.traffic["seq_len"])
 
 
@@ -158,7 +200,8 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
         devices, peak_row = jax.devices(), None
     workdir = workdir or os.path.join(bench.root, WORKDIR_NAME)
     os.makedirs(workdir, exist_ok=True)
-    run = drive.Run(config, traffic, seed, seconds, trace, workdir,
+    run = drive.Run(config, traffic, bench.reference(config),
+                    bench.program(config), seed, seconds, trace, workdir,
                     devices=devices[:cell["chips"]])
     outcome = run.run()
     e2e = end_to_end_values(outcome, t_proc0)

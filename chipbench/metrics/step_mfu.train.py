@@ -1,8 +1,8 @@
 """Model FLOP utilisation of the step program while it runs: the model
-FLOPs of the step runs in the device trace (``flops.py``, from shapes)
-over chips x bf16 peak x the summed device time of those runs.  Idle time
-between steps is not in the denominator: ``device_idle_share.train``
-reports it."""
+FLOPs of the step runs in the device trace (counted from shapes by the
+configuration's reference module) over chips x bf16 peak x the summed
+device time of those runs.  Idle time between steps is not in the
+denominator: ``device_idle_share.train`` reports it."""
 STEP_MODULE = "jit_step"
 
 

@@ -30,6 +30,10 @@ and every gradient flowing back into a matmul input rounded to e5m2 under
 its own scale.
 ``fault="half_batch"`` leaves out half of the tokens and takes the mean
 over the rest.  Both exist to be caught by the comparison.
+
+``train_flops_per_token`` (``chipbench/flops.py``) counts the model FLOPs
+of these equations for a train step, which ``step_mfu.train`` divides by.
+A configuration names this file as its ``"reference"``.
 """
 from __future__ import annotations
 
@@ -40,6 +44,8 @@ from typing import Any, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from chipbench.flops import train_flops_per_token  # noqa: F401
 
 F32 = jnp.float32
 #: Query rows per attention block, and tokens per loss block: each block

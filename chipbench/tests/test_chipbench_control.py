@@ -3,12 +3,13 @@ in fp8 (the precision below the configurations' bfloat16) and the
 reference with half of the batch left out each fail a cell's limits, at a
 size the CPU holds.  The reference draws the program's weights from the
 seed without taking them from the program, bit for bit as the
-program's jitted initialiser (the one train() runs) draws them."""
+program's jitted initialiser (the one train() runs) draws them, each
+through the modules the configuration names."""
 import jax
 import numpy as np
 import pytest
 
-from chipbench import control, drive, reference
+from chipbench import control, harness
 import tiny
 
 CELLS = [c["name"] for c in tiny.TinyBench().doc["workloads"]]
@@ -57,8 +58,10 @@ def test_reference_weights_equal_the_programs(name):
     if config.get("num_local_experts"):
         config.update(num_local_experts=4, num_experts_per_tok=2)
     seed = 2**31 + 9
-    mine = reference.init_params(reference.Arch.from_config(config), seed)
-    theirs = jax.jit(lambda: init_lm(drive.model_config(config),
+    bench = harness.Bench(tiny.ROOT)
+    ref, program = bench.reference(config), bench.program(config)
+    mine = ref.init_params(ref.Arch.from_config(config), seed)
+    theirs = jax.jit(lambda: init_lm(program.model_config(config),
                                      jax.random.PRNGKey(seed)))()
     a = jax.tree_util.tree_leaves_with_path(mine)
     b = jax.tree_util.tree_leaves_with_path(theirs)

@@ -3,18 +3,25 @@
 ``compiled.cost_analysis()`` is not the source: XLA counts the body of a
 ``lax.scan`` once, so the program's scanned layers and chunked loss are
 undercounted, and it counts recomputation (remat) and MoE capacity
-padding, which are not model work."""
+padding, which are not model work.  The train count is read as the
+harness reads it: from the module the configuration names as its
+reference."""
 import json
 
 import pytest
 
-from chipbench import flops
+from chipbench import flops, harness
 from tiny import ROOT
 
 
 def _cfg(name):
     return json.loads((ROOT / "chipbench" / "configs" / f"{name}.json")
                       .read_text())
+
+
+def _train_flops(name, seq_len):
+    c = _cfg(name)
+    return harness.Bench(ROOT).reference(c).train_flops_per_token(c, seq_len)
 
 
 def test_qwen3_l4_by_hand():
@@ -26,8 +33,7 @@ def test_qwen3_l4_by_hand():
     fwd = 2 * (4 * (proj + attn + mlp) + head)                 # 1.0928e9
     assert flops.forward_flops_per_token(_cfg("qwen3-1.7b-l4"), 4096) == \
         pytest.approx(fwd, rel=1e-12)
-    assert flops.train_flops_per_token(_cfg("qwen3-1.7b-l4"), 4096) == \
-        3 * fwd == 3_276_324_864
+    assert _train_flops("qwen3-1.7b-l4", 4096) == 3 * fwd == 3_276_324_864
 
 
 def test_granite_l4_by_hand():
@@ -38,5 +44,5 @@ def test_granite_l4_by_hand():
     fwd = 2 * (4 * (proj + attn + moe) + head)
     assert flops.forward_flops_per_token(
         _cfg("granite-moe-3b-a800m-l4"), 4096) == pytest.approx(fwd, rel=1e-12)
-    assert flops.train_flops_per_token(
-        _cfg("granite-moe-3b-a800m-l4"), 4096) == 3 * fwd == 1_209_498_624
+    assert _train_flops("granite-moe-3b-a800m-l4", 4096) == \
+        3 * fwd == 1_209_498_624
